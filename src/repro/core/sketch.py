@@ -30,6 +30,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core import quantize as qz
 from repro.core.axes import on_auto_axes
 from repro.core.srp import SrpConfig, hash_buckets, make_projections
@@ -255,10 +256,11 @@ def lookup(state: AceState, buckets: jax.Array,
     This is Ŝ(q, D) of Algorithm 1 (query phase).  ``table_mask``
     averages over healthy tables only (see ``batch_scores``).
     """
-    if state.esc is not None:
-        return qz.batch_scores_logical(state.counts, state.esc, buckets,
-                                       table_mask=table_mask)
-    return batch_scores(state.counts, buckets, table_mask=table_mask)
+    with obs.scope("score"):
+        if state.esc is not None:
+            return qz.batch_scores_logical(state.counts, state.esc, buckets,
+                                           table_mask=table_mask)
+        return batch_scores(state.counts, buckets, table_mask=table_mask)
 
 
 def histogram(buckets: jax.Array, cfg: AceConfig) -> jax.Array:
@@ -394,31 +396,34 @@ def insert_buckets_masked(state: AceState, buckets: jax.Array,
     see Guardrail.admit).
     """
     L = cfg.num_tables
-    if state.esc is not None:
-        offs = _flat_offsets(buckets, L, cfg.num_buckets)
-        new_counts, new_esc, post = qz.quantized_scatter(
-            state.counts, state.esc, offs, mask.astype(jnp.int32))
-        # post holds every item's exact post-scatter logical counts —
-        # masked-out items included (colliding admits may bump their
-        # buckets) — which is exactly the batch_scores(new_counts, ·)
-        # gather of the unquantized path.
-        scores = jnp.sum(post.astype(jnp.float32), axis=-1) \
-            * jnp.float32(1.0 / L)
-    else:
-        rows = jnp.broadcast_to(
-            jnp.arange(L, dtype=jnp.int32)[None, :], buckets.shape)
-        w_ctr = jnp.broadcast_to(
-            mask.astype(state.counts.dtype)[:, None], buckets.shape)
-        new_counts = state.counts.at[rows, buckets].add(w_ctr)
-        new_esc = None
+    with obs.scope("insert"):
+        if state.esc is not None:
+            offs = _flat_offsets(buckets, L, cfg.num_buckets)
+            new_counts, new_esc, post = qz.quantized_scatter(
+                state.counts, state.esc, offs, mask.astype(jnp.int32))
+        else:
+            rows = jnp.broadcast_to(
+                jnp.arange(L, dtype=jnp.int32)[None, :], buckets.shape)
+            w_ctr = jnp.broadcast_to(
+                mask.astype(state.counts.dtype)[:, None], buckets.shape)
+            new_counts = state.counts.at[rows, buckets].add(w_ctr)
+            new_esc = post = None
 
-        # Post-insert scores of ALL items vs the fully updated arrays
-        # (the masked-out items just don't contribute to the Welford fold
-        # below).
-        scores = batch_scores(new_counts, buckets)              # (B,)
-
-    tot, new_mean, new_m2 = masked_batch_welford(
-        state, scores, mask.astype(jnp.float32), cfg.welford_min_n)
+    with obs.scope("rescore"):
+        if post is not None:
+            # post holds every item's exact post-scatter logical counts —
+            # masked-out items included (colliding admits may bump their
+            # buckets) — which is exactly the batch_scores(new_counts, ·)
+            # gather of the unquantized path.
+            scores = jnp.sum(post.astype(jnp.float32), axis=-1) \
+                * jnp.float32(1.0 / L)
+        else:
+            # Post-insert scores of ALL items vs the fully updated arrays
+            # (the masked-out items just don't contribute to the Welford
+            # fold below).
+            scores = batch_scores(new_counts, buckets)          # (B,)
+        tot, new_mean, new_m2 = masked_batch_welford(
+            state, scores, mask.astype(jnp.float32), cfg.welford_min_n)
     return AceState(counts=new_counts, n=tot,
                     welford_mean=new_mean, welford_m2=new_m2, esc=new_esc,
                     qhist=state.qhist, attr=state.attr)
@@ -600,12 +605,15 @@ def admit_threshold(state: AceState, alpha: float,
             raise ValueError("threshold_mode='quantile' needs a sketch "
                              "with an attached qhist leaf "
                              "(see repro.quantile.sketch.init_hist)")
-        return qsk.quantile_threshold(state.qhist, state.n, q, warmup_items)
+        with obs.scope("threshold"):
+            return qsk.quantile_threshold(state.qhist, state.n, q,
+                                          warmup_items)
     if threshold_mode != "mu_sigma":
         raise ValueError(f"unknown threshold_mode {threshold_mode!r}")
-    t = (mean_rate(state, table_mask=table_mask)
-         - alpha * sigma_welford(state)) * jnp.maximum(state.n, 1.0)
-    return jnp.where(state.n >= warmup_items, t, -jnp.inf)
+    with obs.scope("threshold"):
+        t = (mean_rate(state, table_mask=table_mask)
+             - alpha * sigma_welford(state)) * jnp.maximum(state.n, 1.0)
+        return jnp.where(state.n >= warmup_items, t, -jnp.inf)
 
 
 def sigma_cubic_proxy(state: AceState) -> jax.Array:

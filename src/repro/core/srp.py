@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
+
 LANE = 128  # TPU vector lane width; MXU is 128x128.
 
 
@@ -149,10 +151,11 @@ def hash_buckets(x: jax.Array, w: jax.Array, cfg: SrpConfig) -> jax.Array:
     a row sample derived from ``cfg.seed``) but keeps its place in the
     signature so the two families are drop-in interchangeable.
     """
-    if resolve_hash_mode(cfg) == "srht":
-        from repro.core import srht  # local: srht imports this module
-        return srht.srht_hash_buckets(x, srht.srht_params(cfg))
-    return pack_buckets(srp_bits(x, w, cfg), cfg)
+    with obs.scope("hash"):
+        if resolve_hash_mode(cfg) == "srht":
+            from repro.core import srht  # local: srht imports this module
+            return srht.srht_hash_buckets(x, srht.srht_params(cfg))
+        return pack_buckets(srp_bits(x, w, cfg), cfg)
 
 
 def collision_probability(q: jax.Array, x: jax.Array) -> jax.Array:

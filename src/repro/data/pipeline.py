@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core import sketch as sk
 from repro.core import srp
 from repro.core.axes import on_auto_axes
@@ -95,10 +96,11 @@ def mean_embed_features(embeds: jax.Array, bias_const: float) -> jax.Array:
     so frozen-vs-windowed comparisons (and the E=1 bitwise contract)
     rest on identical features by construction, not by copy-sync.
     """
-    f = jnp.mean(embeds.astype(jnp.float32), axis=1)
-    f = f / (jnp.linalg.norm(f, axis=-1, keepdims=True) + 1e-9)
-    bias = jnp.full((f.shape[0], 1), bias_const, jnp.float32)
-    return jnp.concatenate([f, bias], axis=-1)
+    with obs.scope("features"):
+        f = jnp.mean(embeds.astype(jnp.float32), axis=1)
+        f = f / (jnp.linalg.norm(f, axis=-1, keepdims=True) + 1e-9)
+        bias = jnp.full((f.shape[0], 1), bias_const, jnp.float32)
+        return jnp.concatenate([f, bias], axis=-1)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -54,6 +54,7 @@ from typing import Optional, Sequence, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core import sketch as sk
 from repro.core.sketch import AceConfig, AceState
 
@@ -296,12 +297,13 @@ def fleet_scores(state: FleetState, tenant_ids: jax.Array,
     branch keeps the healthy program untouched.
     """
     L = state.counts.shape[1]
-    gathered = fleet_table_gather(state.counts, tenant_ids, buckets)
-    if table_mask is None:
-        return jnp.sum(gathered, axis=-1) * jnp.float32(1.0 / L)
-    maskf = table_mask.astype(jnp.float32)[tenant_ids]           # (B, L)
-    nh = jnp.maximum(jnp.sum(maskf, axis=-1), 1.0)               # (B,)
-    return jnp.sum(gathered * maskf, axis=-1) * (1.0 / nh)
+    with obs.scope("score"):
+        gathered = fleet_table_gather(state.counts, tenant_ids, buckets)
+        if table_mask is None:
+            return jnp.sum(gathered, axis=-1) * jnp.float32(1.0 / L)
+        maskf = table_mask.astype(jnp.float32)[tenant_ids]       # (B, L)
+        nh = jnp.maximum(jnp.sum(maskf, axis=-1), 1.0)           # (B,)
+        return jnp.sum(gathered * maskf, axis=-1) * (1.0 / nh)
 
 
 def _tenant_onehot(tenant_ids: jax.Array, num_tenants: int) -> jax.Array:
@@ -363,21 +365,24 @@ def insert_masked(state: FleetState, tenant_ids: jax.Array,
     no rows — no per-tenant branching anywhere.
     """
     T, L, nbuckets = state.counts.shape
-    rows = tenant_ids[:, None] * L + jnp.arange(L, dtype=jnp.int32)[None, :]
-    w_ctr = jnp.broadcast_to(
-        mask.astype(state.counts.dtype)[:, None], buckets.shape)
-    new_counts = state.counts.reshape(T * L, nbuckets) \
-        .at[rows, buckets].add(w_ctr).reshape(state.counts.shape)
+    with obs.scope("insert"):
+        rows = tenant_ids[:, None] * L \
+            + jnp.arange(L, dtype=jnp.int32)[None, :]
+        w_ctr = jnp.broadcast_to(
+            mask.astype(state.counts.dtype)[:, None], buckets.shape)
+        new_counts = state.counts.reshape(T * L, nbuckets) \
+            .at[rows, buckets].add(w_ctr).reshape(state.counts.shape)
 
     # Post-insert scores of ALL items vs their own tenant's updated
     # tables (Algorithm 1 line 12's x-vs-D∪{x} convention, same as every
-    # other insert path).
-    new_state_counts = state._replace(counts=new_counts)
-    scores = fleet_scores(new_state_counts, tenant_ids, buckets)  # (B,)
-
-    tot, new_mean, new_m2 = fleet_masked_welford(
-        state, tenant_ids, scores, mask.astype(jnp.float32),
-        cfg.welford_min_n)
+    # other insert path).  The outer scope names the phase: the score
+    # helper's own scope nests inside it.
+    with obs.scope("rescore"):
+        new_state_counts = state._replace(counts=new_counts)
+        scores = fleet_scores(new_state_counts, tenant_ids, buckets)
+        tot, new_mean, new_m2 = fleet_masked_welford(
+            state, tenant_ids, scores, mask.astype(jnp.float32),
+            cfg.welford_min_n)
     return FleetState(counts=new_counts, n=tot,
                       welford_mean=new_mean, welford_m2=new_m2,
                       qhist=state.qhist, attr=state.attr)
@@ -442,15 +447,17 @@ def admit_thresholds(state: FleetState, alpha: float,
         if state.qhist is None:
             raise ValueError("threshold_mode='quantile' needs a fleet "
                              "initialised with quantile=True")
-        rates = jax.vmap(lambda h: qsk.hist_quantile(h, q))(state.qhist)
-        t = rates * jnp.maximum(state.n, 1.0)
-        return jnp.where(state.n >= warmup_items, t, -jnp.inf)
+        with obs.scope("threshold"):
+            rates = jax.vmap(lambda h: qsk.hist_quantile(h, q))(state.qhist)
+            t = rates * jnp.maximum(state.n, 1.0)
+            return jnp.where(state.n >= warmup_items, t, -jnp.inf)
     if threshold_mode != "mu_sigma":
         raise ValueError(f"unknown threshold_mode {threshold_mode!r}")
-    t = (mean_rate_fleet(state, table_mask=table_mask)
-         - alpha * sigma_welford_fleet(state)) \
-        * jnp.maximum(state.n, 1.0)
-    return jnp.where(state.n >= warmup_items, t, -jnp.inf)
+    with obs.scope("threshold"):
+        t = (mean_rate_fleet(state, table_mask=table_mask)
+             - alpha * sigma_welford_fleet(state)) \
+            * jnp.maximum(state.n, 1.0)
+        return jnp.where(state.n >= warmup_items, t, -jnp.inf)
 
 
 def per_tenant_counts(tenant_ids: jax.Array, values: jax.Array,

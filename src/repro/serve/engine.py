@@ -5,7 +5,6 @@ before touching the model — the paper's query phase as an admission filter).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, NamedTuple
 
 import jax
@@ -632,16 +631,3 @@ class ServeEngine:
             toks.append(tok)
         return _to_host(jnp.stack(toks, axis=1))    # the ONE transfer
 
-
-def decode_throughput(arch: Arch, params, cache, batch, pos,
-                      iters: int = 8) -> float:
-    """tokens/sec of the jitted decode step (host-timed)."""
-    step = jax.jit(arch.decode_step)
-    logits, cache = step(params, batch, cache, pos)   # compile
-    jax.block_until_ready(logits)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        logits, cache = step(params, batch, cache, pos)
-    jax.block_until_ready(logits)
-    dt = (time.perf_counter() - t0) / iters
-    return batch[next(iter(batch))].shape[0] / dt

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import obs
 from repro.core.axes import on_auto_axes
 from repro.core.sketch import AceState
 from repro.data.pipeline import AceDataFilter
@@ -215,6 +216,9 @@ class StreamRunner:
                 f"multiple of chunk_T={self.chunk_T} so epoch boundaries "
                 "land deterministically inside or between chunks")
         self.trace_count = 0          # incremented at TRACE time only
+        # sequence number of the next chunk, the ``chunk`` argument of its
+        # ``ace:stream/consume`` span
+        self.chunks_consumed = 0
         # Heavy-hitter attribution: non-None when the filter carries
         # attr planes (attr_rows > 0) — the consume program then also
         # observes per-chunk energy sketches and drills down for the
@@ -291,9 +295,10 @@ class StreamRunner:
 
             state, (keeps, margins) = jax.lax.scan(
                 fstep, state, (feats, tenant_ids))
-            return self._fleet_summary(state, keeps, margins,
-                                       tenant_ids, feats, T, B,
-                                       table_mask, tenant_mask)
+            with obs.scope("summary"):
+                return self._fleet_summary(state, keeps, margins,
+                                           tenant_ids, feats, T, B,
+                                           table_mask, tenant_mask)
 
         def step(carry, feat):
             new_state, keep, margin = self.filt.step(
@@ -338,6 +343,14 @@ class StreamRunner:
             state = maybe_rotate(state, R, gamma)
         else:
             state, (keeps, margins) = jax.lax.scan(step, state, feats)
+        with obs.scope("summary"):
+            return self._flat_summary(state, keeps, margins, feats, T, B,
+                                      gamma, table_mask)
+
+    def _flat_summary(self, state, keeps, margins, feats, T, B, gamma,
+                      table_mask=None):
+        """The chunk's ``ChunkSummary`` from the scan outputs — all device
+        reductions, one transfer with the keep masks."""
         keepf = keeps.astype(jnp.float32)                     # (T, B)
         k = min(self.topk, T * B)
         # top-k most anomalous = smallest margins — but quarantined rows
@@ -502,7 +515,10 @@ class StreamRunner:
         produced by ``AceDataFilter.features``), plus the (T, B) int32
         tenant-id plane when the filter is a fleet.  Returns
         (new_state, summary[, keeps]) — all still on device; pull the
-        summary with ONE ``jax.device_get`` when the host needs it.
+        summary with ONE ``jax.device_get`` when the host needs it.  The
+        dispatch is one ``ace:stream/consume`` host span (``repro.core
+        .obs``) whose arguments are the chunk's sequence number
+        (``chunks_consumed`` before the call) and its T·B items.
 
         ``table_mask`` ((L,) or (T, L) f32, repro.resilience serving
         mask) scores the chunk over healthy tables only and stamps the
@@ -522,13 +538,18 @@ class StreamRunner:
             assert tenant_ids is not None and \
                 tenant_ids.shape == feats.shape[:2], \
                 "fleet filters need a (T, B) tenant_ids plane"
-            return self._consume(state, w, feats, tenant_ids, table_mask,
-                                 tenant_mask)
-        assert tenant_ids is None, \
-            "tenant_ids given but the filter is not a fleet"
-        assert tenant_mask is None, \
-            "tenant_mask needs a fleet filter"
-        return self._consume(state, w, feats, None, table_mask)
+            args = (tenant_ids, table_mask, tenant_mask)
+        else:
+            assert tenant_ids is None, \
+                "tenant_ids given but the filter is not a fleet"
+            assert tenant_mask is None, \
+                "tenant_mask needs a fleet filter"
+            args = (None, table_mask)
+        with obs.span("stream/consume", chunk=self.chunks_consumed,
+                      items=feats.shape[0] * feats.shape[1]):
+            out = self._consume(state, w, feats, *args)
+        self.chunks_consumed += 1
+        return out
 
     def run(self, state: AceState, w: jax.Array,
             batches: Iterable[np.ndarray], tenant_ids=None):

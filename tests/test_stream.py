@@ -3,7 +3,9 @@ SRHT Pallas kernel parity, hash_mode dispatch, the filter's hash-once and
 Welford-delegation contracts, and the serve decode loop's single-transfer
 contract."""
 import dataclasses
+import functools
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -422,3 +424,61 @@ class TestServeDecodeTransfers:
         out2 = eng.generate(params, {"tokens": toks}, num_new_tokens=6,
                             prompt_len=8)
         np.testing.assert_array_equal(out, out2)
+
+
+# ---------------------------------------------------------------------------
+# Phase scopes and the consume span (repro.core.obs): what a profile of the
+# stream path can name.
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _compiled_consume_text(fleet: bool) -> str:
+    """Optimized HLO text of a small consume program, flat or fleet."""
+    if fleet:
+        from repro.fleet import FleetDataFilter
+        filt = FleetDataFilter(d_model=8, num_tenants=3, num_bits=6,
+                               num_tables=4, warmup_items=16.0)
+    else:
+        filt = AceDataFilter(d_model=8, num_bits=6, num_tables=4,
+                             warmup_items=16.0)
+    runner = StreamRunner(filt, chunk_T=3, topk=4)
+    state, w = runner.init()
+    feats = jnp.ones((3, 16, 9), jnp.float32)
+    args = (state, w, feats) + (
+        (jnp.zeros((3, 16), jnp.int32),) if fleet else ())
+    return runner._consume.lower(*args).compile().as_text()
+
+
+
+class TestPhaseScopes:
+    @pytest.mark.parametrize("fleet,phase", [
+        (False, "hash"), (False, "score"), (False, "threshold"),
+        (False, "insert"), (False, "rescore"), (False, "summary"),
+        (True, "score"), (True, "threshold"), (True, "insert"),
+        (True, "rescore"), (True, "summary")])
+    def test_consume_program_carries_phase_scopes(self, fleet, phase):
+        text = _compiled_consume_text(fleet)
+        assert 'op_name="jit(_consume_impl)/' in text
+        assert re.search(rf'op_name="[^"]*/ace/{phase}/', text), phase
+
+    def test_features_carry_their_scope(self):
+        filt = AceDataFilter(d_model=8)
+        text = jax.jit(filt.features).lower(
+            jnp.ones((4, 2, 8), jnp.float32)).compile().as_text()
+        assert re.search(r'op_name="[^"]*ace/features/', text)
+
+    def test_rescore_names_the_phase_around_a_nested_score(self):
+        """The outermost scope names an op's phase: the fleet's post-insert
+        re-score runs the score helper inside the ``rescore`` scope."""
+        text = _compiled_consume_text(True)
+        assert re.search(r'op_name="[^"]*/ace/rescore/ace/score/', text)
+
+    def test_consume_counts_its_chunks(self):
+        filt = AceDataFilter(d_model=8, num_bits=6, num_tables=4)
+        runner = StreamRunner(filt, chunk_T=2)
+        state, w = runner.init()
+        assert runner.chunks_consumed == 0
+        for _ in range(3):
+            state, _ = runner.consume(state, w,
+                                      jnp.ones((2, 8, 9), jnp.float32))
+        assert runner.chunks_consumed == 3 and runner.trace_count == 1

@@ -186,7 +186,10 @@ class AceDataFilter:
         max(n, 1) > 0); the insert + Welford fold delegate to
         ``sk.insert_buckets_masked`` → ``sk.masked_batch_welford``, the
         same single-homed helpers as the serving guardrail and both
-        ``repro.dist`` layouts.  Two behaviour notes vs the old inline
+        ``repro.dist`` layouts.  The table is read once: the score's
+        gather (``sk.lookup_gathered``, unmasked) goes to the insert as
+        ``pre``, which derives the post-insert scores from it.  Two
+        behaviour notes vs the old inline
         block (both unifications, property-tested in tests/test_stream.py):
         the Welford stream now folds POST-insert scores (Algorithm 1 line
         12's x-vs-D∪{x} convention, like every other insert path) where
@@ -198,8 +201,8 @@ class AceDataFilter:
         finite = jnp.all(jnp.isfinite(feat), axis=-1)
         feat = jnp.where(finite[:, None], feat, 0.0)
         buckets = srp.hash_buckets(feat, w, cfg.srp)   # the ONE hash
-        scores = sk.lookup(state, buckets,             # same bucket ids
-                           table_mask=table_mask)
+        scores, pre = sk.lookup_gathered(              # same bucket ids
+            state, buckets, table_mask=table_mask)
         thresh = sk.admit_threshold(state, self.alpha, self.warmup_items,
                                     table_mask=table_mask,
                                     threshold_mode=self.threshold_mode,
@@ -207,7 +210,8 @@ class AceDataFilter:
         keep = jnp.logical_and(scores >= thresh, finite)
         margin = jnp.where(finite, scores - thresh, -jnp.inf)
         ins = finite if self.insert_all else keep
-        new_state = sk.insert_buckets_masked(state, buckets, ins, cfg)
+        new_state = sk.insert_buckets_masked(state, buckets, ins, cfg,
+                                             pre=pre)
         if self.threshold_mode == "quantile":
             # Calibration stream: EVERY finite-scored item feeds the rate
             # histogram — observing only admitted items would freeze the

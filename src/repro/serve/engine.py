@@ -442,15 +442,16 @@ class Guardrail:
                                   threshold_mode=self.gcfg.threshold_mode,
                                   quantile_q=self.gcfg.quantile_q)
         buckets = hash_buckets(feat, w, cfg.srp)       # the ONE hash
-        scores = sk.lookup(state, buckets,             # same bucket ids
-                           table_mask=table_mask)
+        scores, pre = sk.lookup_gathered(              # same bucket ids
+            state, buckets, table_mask=table_mask)
         admit = scores >= sk.admit_threshold(
             state, self.gcfg.alpha, self.gcfg.warmup_items,
             table_mask=table_mask,
             threshold_mode=self.gcfg.threshold_mode,
             q=self.gcfg.quantile_q)
         admit = jnp.logical_and(admit, finite)
-        new_state = sk.insert_buckets_masked(state, buckets, admit, cfg)
+        new_state = sk.insert_buckets_masked(state, buckets, admit, cfg,
+                                             pre=pre)
         if self.gcfg.threshold_mode == "quantile":
             from repro.quantile import sketch as qsk
             rates = scores / jnp.maximum(state.n, 1.0)

@@ -86,6 +86,101 @@ class TestMaskedInsertEquivalence:
         assert_allclose_dtype(got.welford_mean, want.welford_mean)
         assert_allclose_dtype(got.welford_m2, want.welford_m2)
 
+    @pytest.mark.parametrize("B", [1, 7, 249])
+    @pytest.mark.parametrize("K", [2, 3, 8])
+    @pytest.mark.parametrize("mask_kind", ["random", "ones", "zeros"])
+    @pytest.mark.parametrize("dup", [False, True])
+    def test_derived_post_insert_equals_the_gather(self, B, K, mask_kind,
+                                                   dup):
+        """The post-insert counts derived from the pre-insert gather plus
+        the in-batch collisions equal the gather from the updated table
+        exactly, and the insert that uses them (``pre=``) equals the one
+        that gathers again, bitwise: counts, n, Welford.  2^K <= 8 forces
+        heavy collisions; ``dup`` repeats whole rows of bucket ids."""
+        L = 5
+        cfg = AceConfig(dim=6, num_bits=K, num_tables=L, seed=1,
+                        welford_min_n=4.0)
+        state, _, rng = _seeded_state(cfg, 100 * B + K)
+        ids = rng.integers(0, 1 << K, size=(B, L))
+        if dup and B > 1:
+            ids[1::3] = ids[0]
+        buckets = jnp.asarray(ids, jnp.int32)
+        mask = jnp.asarray({"random": rng.random(B) < 0.7,
+                            "ones": np.ones(B, bool),
+                            "zeros": np.zeros(B, bool)}[mask_kind])
+
+        pre = sk.gather_counts(state.counts, buckets)
+        got = sk.insert_buckets_masked(state, buckets, mask, cfg, pre=pre)
+        want = sk.insert_buckets_masked(state, buckets, mask, cfg)
+        post = pre + sk.batch_collisions(buckets, mask)
+        np.testing.assert_array_equal(
+            np.asarray(post), np.asarray(sk.gather_counts(want.counts,
+                                                          buckets)))
+        for name in ("counts", "n", "welford_mean", "welford_m2"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+
+    def test_collisions_match_a_loop(self):
+        """``batch_collisions`` against the definition, item by item."""
+        rng = np.random.default_rng(3)
+        ids = rng.integers(0, 4, size=(33, 6))
+        w = rng.random(33) < 0.5
+        got = np.asarray(sk.batch_collisions(jnp.asarray(ids, jnp.int32),
+                                             jnp.asarray(w)))
+        want = np.array([[int(np.sum(w & (ids[:, l] == ids[i, l])))
+                          for l in range(6)] for i in range(33)])
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("B,gathers", [
+        (sk.PAIRWISE_MAX_BATCH, 1), (sk.PAIRWISE_MAX_BATCH + 1, 2)])
+    def test_large_batch_gathers_again(self, B, gathers):
+        """Past ``PAIRWISE_MAX_BATCH`` items the quadratic collision count
+        would cost more than the gather, so the insert given ``pre``
+        gathers again (traced: one gather, the caller's, or two), with
+        the same result either way."""
+        cfg = AceConfig(dim=4, num_bits=4, num_tables=1)
+        state = sk.init(cfg)
+        ids = jnp.asarray(np.random.default_rng(4).integers(
+            0, 16, size=(B, 1)), jnp.int32)
+        mask = jnp.ones(B, bool)
+
+        def insert(counts, ids):
+            st = state._replace(counts=counts)
+            return sk.insert_buckets_masked(
+                st, ids, mask, cfg, pre=sk.gather_counts(counts, ids))
+
+        jaxpr = str(jax.make_jaxpr(insert)(state.counts, ids))
+        assert jaxpr.count(" gather[") == gathers
+        got = insert(state.counts, ids)
+        want = sk.insert_buckets_masked(state, ids, mask, cfg)
+        for name in ("counts", "n", "welford_mean", "welford_m2"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+
+    @pytest.mark.parametrize("mask_kind", ["random", "ones", "zeros"])
+    def test_derived_insert_matches_replicated_update(self, mask_kind):
+        """The replicated ``repro.dist`` layout, which gathers its own
+        post-insert scores, stays bitwise equal to the derived insert."""
+        from repro.dist.sketch_parallel import update_global_masked
+        cfg = AceConfig(dim=6, num_bits=3, num_tables=7, seed=2,
+                        welford_min_n=4.0)
+        state, w, rng = _seeded_state(cfg, 9)
+        x = jnp.asarray(rng.normal(size=(40, 6)), jnp.float32)
+        mask = jnp.asarray({"random": rng.random(40) < 0.6,
+                            "ones": np.ones(40, bool),
+                            "zeros": np.zeros(40, bool)}[mask_kind])
+        buckets = sk.hash_buckets(x, w, cfg.srp)
+        got = sk.insert_buckets_masked(
+            state, buckets, mask, cfg,
+            pre=sk.gather_counts(state.counts, buckets))
+        want = update_global_masked(state, x, w, mask, cfg)
+        for name in ("counts", "n", "welford_mean", "welford_m2"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+
 
 class TestAdmitThreshold:
     def test_warmup_is_minus_inf(self):

@@ -247,6 +247,47 @@ class TestFilterContracts:
         filt.step(state, w, filt.features(e))
         assert len(calls) == 1
 
+    @pytest.mark.parametrize("insert_all", [False, True])
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_step_equals_lookup_then_gathering_insert(self, insert_all,
+                                                      masked):
+        """``step`` scores from its one gather and derives the post-insert
+        scores from it; composed from ``sk.lookup`` and the insert that
+        gathers again, the same step gives the same state, keep and
+        margin, bitwise — with a degraded ``table_mask`` too, which masks
+        the score and not the post-insert counts."""
+        filt = AceDataFilter(d_model=16, num_bits=3, num_tables=6,
+                             warmup_items=16.0, alpha=0.5,
+                             insert_all=insert_all)
+        cfg = filt.ace_cfg
+        state, w = filt.init()
+        rng = np.random.default_rng(5)
+        for _ in range(3):
+            state, _, _ = filt.step(state, w, filt.features(_embeds(rng)))
+        tm = (jnp.asarray([1, 1, 0, 1, 0, 1], jnp.float32) if masked
+              else None)
+        feat = filt.features(jnp.concatenate(
+            [_embeds(rng, B=12), _embeds(rng, B=12, mu=0.0, scale=1.0)]))
+        got, keep, margin = filt.step(state, w, feat, table_mask=tm)
+
+        buckets = srp.hash_buckets(feat, w, cfg.srp)
+        scores = sk.lookup(state, buckets, table_mask=tm)
+        thresh = sk.admit_threshold(state, filt.alpha, filt.warmup_items,
+                                    table_mask=tm)
+        want_keep = scores >= thresh
+        want = sk.insert_buckets_masked(
+            state, buckets, jnp.ones_like(want_keep) if insert_all
+            else want_keep, cfg)
+        assert 0 < int(keep.sum()) < 24 or insert_all
+        np.testing.assert_array_equal(np.asarray(keep),
+                                      np.asarray(want_keep))
+        np.testing.assert_array_equal(np.asarray(margin),
+                                      np.asarray(scores - thresh))
+        for name in ("counts", "n", "welford_mean", "welford_m2"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+
     def test_masked_welford_matches_old_inline_formulas_bitwise(self):
         """The pre-rewrite hand-rolled Welford block of
         AceDataFilter.__call__, fed the same (state, scores, keep), must
@@ -472,6 +513,18 @@ class TestPhaseScopes:
         re-score runs the score helper inside the ``rescore`` scope."""
         text = _compiled_consume_text(True)
         assert re.search(r'op_name="[^"]*/ace/rescore/ace/score/', text)
+
+    def test_flat_step_reads_the_table_once(self):
+        """The flat scan step gathers from the counts once, for the score:
+        its post-insert scores come from that gather and the batch's own
+        collisions.  The fleet step still re-gathers for its re-score."""
+        def gathers(text):
+            return re.findall(r'= \S+ gather\([^\n]*op_name="([^"]*)"', text)
+
+        flat = gathers(_compiled_consume_text(False))
+        assert len(flat) == 1 and "/ace/score/" in flat[0], flat
+        fleet = gathers(_compiled_consume_text(True))
+        assert any("/ace/rescore/ace/score/" in g for g in fleet), fleet
 
     def test_consume_counts_its_chunks(self):
         filt = AceDataFilter(d_model=8, num_bits=6, num_tables=4)

@@ -1,0 +1,9 @@
+"""Self time of the device ops in the program's ``ace/rescore`` scope (the
+post-insert counts and the Welford fold), per 1,000 items of the consume
+spans in the traced window (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.readings(ctx.scopes).get("rescore_device_us_per_kitem")

@@ -15,6 +15,28 @@ program's ``src/``.  A compile inside the measured window is an error.  After
 the window the outputs are compared with the plain reference; each number
 compared is printed with its limit as the last lines on standard error.  The
 last line on standard output is the result, one JSON object.
+
+Everything is found by the names in ``BENCHMARK.json``, so what follows is
+added as new files and new entries there, and no file that exists is edited.
+
+* A configuration: its entry in ``configs``; the file it names,
+  ``bench/configs/<config>.json``, with its ``kind``, its sizes, its
+  ``limits`` (number compared -> limit), and, for each key it lists in
+  ``reduced``, the source's value under ``published``;
+  ``bench/work/<config>.py``, whose ``per_item(cfg)`` gives the algorithm's
+  flops and bytes per item; and, for a new ``kind``,
+  ``bench/kinds/<kind>.py``, the hooks the tests find by the kind's name
+  (``tiny``, ``control_numbers``, ``FAULTS``, ``plant``), a driver
+  ``bench/traffic/<driver>.py`` whose ``run(r)`` returns an ``Outcome``, and
+  its plain reference under ``bench/reference/``.
+* A cell: its entry in ``workloads``, its name added to the ``workloads``
+  of each end-to-end metric it reports, and its mix
+  ``bench/traffic/<traffic>.json``, data only, naming its driver.
+* A per-layer metric: its entry in ``per_layer`` and
+  ``bench/metrics/<metric>.py``, whose ``read(ctx)`` takes a ``Context``
+  and returns a number, or ``None`` where it finds nothing to read.  A
+  device scope ``ace/<phase>`` of the program is read as
+  ``bench.scopes.readings(ctx.scopes)["<phase>_device_us_per_kitem"]``.
 """
 from __future__ import annotations
 
@@ -137,6 +159,7 @@ class Context:
     """What a per-layer metric's reader gets."""
 
     trace: object              # bench.trace.TraceSummary
+    scopes: object             # bench.scopes.ScopeSummary
     samples: dict
     cfg: dict
     mix: dict
@@ -168,6 +191,12 @@ def _compile_cache() -> str:
     return path
 
 
+def reduce_scopes(path: str):
+    """The program's scopes and spans in a trace (``bench/scopes.py``)."""
+    from bench import scopes
+    return scopes.reduce(path)
+
+
 @dataclasses.dataclass
 class Driven:
     """A cell driven once: its specification, the run and its outcome."""
@@ -180,6 +209,7 @@ class Driven:
     run: Run
     out: Outcome
     trace: object              # TraceSummary of a traced run, else None
+    scopes: object             # ScopeSummary of a traced run, else None
     peaks: dict
 
 
@@ -201,6 +231,14 @@ def drive(name: str, seed: int, seconds: float, trace: bool, *,
         raise Refused(f"no peaks for device kind {devices[0].device_kind!r}"
                       " in bench/peaks.json")
     cache = _compile_cache()
+    if trace:
+        # Without the op names in the key, the cache may hand a traced run
+        # an executable built from the same program with other op names
+        # (another commit's, without the scopes), and its trace carries
+        # those.
+        import jax
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     print(f"workload={name} seed={seed} seconds={seconds} trace={int(trace)} "
           f"compile_cache={cache} devices_ready_s="
           f"{common.clock() - T_START:.3f}", file=sys.stderr, flush=True)
@@ -215,11 +253,28 @@ def drive(name: str, seed: int, seconds: float, trace: bool, *,
         out = driver.run(r)
         path = r.tracer.stop()
         summary = reduce_trace(path) if trace else None
+        scoped = reduce_scopes(path) if trace else None
     finally:
         r.tracer.cleanup()
     return Driven(bench=bench, workload=w, cfg=cfg, mix=mix, devices=devices,
-                  run=r, out=out, trace=summary,
+                  run=r, out=out, trace=summary, scopes=scoped,
                   peaks=peaks.get(devices[0].device_kind, {}))
+
+
+def per_layer(d: Driven, wanted: list) -> dict:
+    """The per-layer metrics of a traced run, each read by its file;
+    those whose reader finds nothing to read are left out."""
+    ctx = Context(trace=d.trace, scopes=d.scopes, samples=d.out.samples,
+                  cfg=d.cfg, mix=d.mix, work=common.load_module(os.path.join(
+                      common.BENCH, "work", d.workload["config"] + ".py")),
+                  peaks=d.peaks)
+    metrics = {}
+    for m in wanted:
+        v = common.load_module(os.path.join(
+            common.BENCH, "metrics", m["name"] + ".py")).read(ctx)
+        if v is not None:
+            metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    return metrics
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool,
@@ -231,15 +286,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     metrics = {}
     wanted = cell_metrics(name, d.bench, trace)
     if trace:
-        ctx = Context(trace=d.trace, samples=out.samples, cfg=d.cfg,
-                      mix=d.mix, work=common.load_module(os.path.join(
-                          common.BENCH, "work", d.workload["config"] + ".py")),
-                      peaks=d.peaks)
-        for m in wanted:
-            v = common.load_module(os.path.join(
-                common.BENCH, "metrics", m["name"] + ".py")).read(ctx)
-            if v is not None:
-                metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+        metrics = per_layer(d, wanted)
     else:
         values = dict(out.metrics, setup_s=d.run.setup_s)
         for m in wanted:

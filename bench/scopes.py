@@ -25,15 +25,19 @@ format here, with the field numbers of ``xplane.proto`` (TensorFlow's
 ``tsl/profiler/protobuf``) and ``hlo.proto`` (XLA).  Nothing here imports
 TensorFlow or xprof.
 
-``readings`` turns a reduction into per-layer numbers of the stream cells.
-Run on a cell, traced:
+``readings`` turns a reduction into per-layer numbers by name, one per
+``ace/<phase>`` found and a few of the spans, and ``bench/run.py`` hands the
+reduction of a traced run to the metric readers (``Context.scopes``).  Run
+on a cell, traced:
 
     python3 bench/scopes.py --workload <cell> --seed <n> --seconds <s> \\
         [--keep <dir>]
 
-prints one JSON line: the readings, the self seconds per phase, the idle
-share, the run's own numbers (profiler on) and ``bench/trace.py``'s
-breakdown; ``--keep`` copies the trace file into ``<dir>``.
+prints one JSON line: the readings, the self seconds per phase, the cell's
+per-layer metrics as its readers give them, the idle share, the run's own
+numbers (profiler on) and ``bench/trace.py``'s breakdown; ``--keep`` copies
+the trace file into ``<dir>``.  ``python3 bench/scopes.py --file <trace>``
+reduces a kept file alone and prints its readings.
 """
 from __future__ import annotations
 
@@ -355,70 +359,76 @@ def pull_after_ready(dispatches, executions, pulls) -> list:
 
 
 def readings(s: ScopeSummary) -> dict:
-    """The stream cells' per-layer readings of a reduction; a reading with
-    nothing to read (a program without the scopes or the span) is left
-    out."""
+    """The per-layer readings of a reduction, by name: for every
+    ``ace/<phase>`` found, ``<phase>_device_us_per_kitem``, its self time
+    per 1,000 items of the ``ace:stream/consume`` spans; the share of the
+    device's self time in no scope (``unscoped_device_share``, in percent);
+    the median consume span (``runner_dispatch_us``) and the median time
+    from a chunk's execution's end to the end of the pull of its verdicts
+    (``pull_after_ready_ms``).  A reading with nothing to read (a program
+    without the scopes or the span) is left out; each
+    ``bench/metrics/<name>.py`` picks its own."""
     consume = [(a, b, st) for a, b, name, st in s.spans
                if name == CONSUME_SPAN]
     items = sum(int(st.get("items", 0)) for _, _, st in consume)
     scoped = {k: v for k, v in s.scope_self_s.items() if k != UNSCOPED}
     out = {}
-    if items and scoped:
-        for phase in ("score", "rescore", "insert"):
-            if phase in scoped:
-                out[f"{phase}_device_us_per_kitem.stream"] = \
-                    1e9 * scoped[phase] / items
+    if items:
+        for phase, seconds in scoped.items():
+            out[f"{phase}_device_us_per_kitem"] = 1e9 * seconds / items
     total = sum(s.scope_self_s.values())
     if scoped and total > 0:
-        out["unscoped_device_share.stream"] = \
+        out["unscoped_device_share"] = \
             100.0 * s.scope_self_s.get(UNSCOPED, 0.0) / total
     if consume:
-        out["runner_dispatch_us.stream"] = 1e-3 * common.median(
+        out["runner_dispatch_us"] = 1e-3 * common.median(
             [b - a for a, b, _ in consume])
     gaps = pull_after_ready(
         [(a, int(st["chunk"])) for a, _, st in consume if "chunk" in st],
         s.modules.get(CONSUME_MODULE, []), s.pulls)
     if gaps:
-        out["pull_after_ready_ms.stream"] = 1e-6 * common.median(gaps)
+        out["pull_after_ready_ms"] = 1e-6 * common.median(gaps)
     return out
 
 
-def main(argv=None) -> int:
-    import jax
+def _summary(s: ScopeSummary) -> dict:
+    return {"readings": readings(s), "scope_self_s": s.scope_self_s,
+            "busy_s": s.busy_s, "window_s": s.window_s}
 
+
+def main(argv=None) -> int:
     from bench import run
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int)
+    ap.add_argument("--seconds", type=float)
     ap.add_argument("--keep", default="")
+    ap.add_argument("--file", default="",
+                    help="reduce this kept trace file instead of a run")
     args = ap.parse_args(argv)
-    # JAX keys its persistent compile cache without metadata by default,
-    # so it may hand this run an executable built from the same program
-    # with other op names (another commit's, without the scopes); its
-    # profile would carry those.
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    held = {}
-
-    def reduce_both(path):
-        held["scopes"] = reduce(path)
-        if args.keep:
+    if args.file:
+        print(json.dumps(dict(file=args.file, **_summary(reduce(args.file)))),
+              flush=True)
+        return 0
+    if args.workload is None or args.seed is None or args.seconds is None:
+        ap.error("--workload, --seed and --seconds, or --file")
+    if args.keep:
+        def reduce_and_keep(path):
             os.makedirs(args.keep, exist_ok=True)
             shutil.copy(path, os.path.join(
                 args.keep, f"{args.workload}.{args.seed}.xplane.pb"))
-        return btrace.reduce(path)
+            return reduce(path)
 
-    run.reduce_trace = reduce_both
+        run.reduce_scopes = reduce_and_keep
     d = run.drive(args.workload, args.seed, args.seconds, True)
-    s = held["scopes"]
-    print(json.dumps({
-        "workload": args.workload, "seed": args.seed,
-        "readings": readings(s), "scope_self_s": s.scope_self_s,
-        "busy_s": s.busy_s, "window_s": s.window_s,
-        "idle_share": d.trace.idle_share, "traced_metrics": d.out.metrics,
-        "span_counts": d.trace.span_counts,
-        "breakdown": d.trace.breakdown()}), flush=True)
+    print(json.dumps(dict(
+        workload=args.workload, seed=args.seed, **_summary(d.scopes),
+        metrics=run.per_layer(d, run.cell_metrics(args.workload, d.bench,
+                                                  True)),
+        idle_share=d.trace.idle_share, traced_metrics=d.out.metrics,
+        span_counts=d.trace.span_counts,
+        breakdown=d.trace.breakdown())), flush=True)
     return 0
 
 

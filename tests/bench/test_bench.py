@@ -21,7 +21,6 @@ if ROOT not in sys.path:
 
 from bench import common, run  # noqa: E402
 from bench import trace as btrace  # noqa: E402
-from bench.reference import ace  # noqa: E402
 from bench.reference import guardrail_fleet as fleet_ref  # noqa: E402
 from bench.reference import stream as stream_ref  # noqa: E402
 
@@ -33,24 +32,43 @@ CONFIGS = {c["name"]: c for c in ALL["configs"]}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
-# Sizes a CPU test can hold; the semantics are the cells' own.
-TINY = {
-    "stream": (dict(rows=2000, anomalies=20, num_bits=8, num_tables=8,
-                    warmup_items=64), dict(chunk_T=2, chunk_B=50)),
-    "guardrail_fleet": (dict(d_model=32, num_tenants=4, num_bits=6,
-                             num_tables=8, warmup_items=16, batch=16),
-                        dict(pool=256)),
-}
+
+def hooks(kind: str):
+    """The test hooks of a configuration kind, ``bench/kinds/<kind>.py``:
+    ``tiny``, ``control_numbers``, ``FAULTS`` and ``plant``."""
+    return common.load_module(os.path.join(common.BENCH, "kinds",
+                                           kind + ".py"))
+
+
+def kind_of(config: str) -> str:
+    return common.load_json(ROOT, CONFIGS[config]["file"])["kind"]
 
 
 def tiny(cell: str):
-    cfg = common.load_json(ROOT, CONFIGS[
-        {w["name"]: w for w in ALL["workloads"]}[cell]["config"]]["file"])
-    over_cfg, over_mix = TINY[cfg["kind"]]
-    mix = dict(over_mix)
-    if cfg["kind"] == "guardrail_fleet":
-        mix["rate_per_s"] = 1500.0
-    return dict(cfg_override=dict(over_cfg), mix_override=mix, pending=True)
+    w = {w["name"]: w for w in ALL["workloads"]}[cell]
+    cfg = common.load_json(ROOT, CONFIGS[w["config"]]["file"])
+    mix = common.load_json(common.BENCH, "traffic", w["traffic"] + ".json")
+    over_cfg, over_mix = hooks(cfg["kind"]).tiny(cfg, mix)
+    return dict(cfg_override=dict(over_cfg), mix_override=dict(over_mix),
+                pending=True)
+
+
+def _fault_cases():
+    """(cell, fault): each fault its kind's hooks plant, in the first cell of
+    each kind that has hooks (a kind without them fails
+    ``test_kind_hooks_found_by_name``)."""
+    cases, seen = [], set()
+    for w in ALL["workloads"]:
+        kind = kind_of(w["config"])
+        if kind in seen or not os.path.isfile(
+                os.path.join(common.BENCH, "kinds", kind + ".py")):
+            continue
+        seen.add(kind)
+        cases += [(w["name"], f) for f in hooks(kind).FAULTS]
+    return cases
+
+
+FAULT_CASES = _fault_cases()
 
 
 @pytest.fixture
@@ -86,12 +104,20 @@ def test_benchmark_json_contract():
     names = set()
     for c in ALL["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert c["file"].startswith("bench/") and c["reduced"] == []
-        assert common.load_json(ROOT, c["file"])["name"] == c["name"]
+        assert c["file"].startswith("bench/")
+        cfg = common.load_json(ROOT, c["file"])
+        assert cfg["name"] == c["name"]
+        assert len(c["reduced"]) <= 16
+        for key in c["reduced"]:
+            # a cut names a key of the file, which states the source's value
+            assert isinstance(key, str) and NAME.match(key), key
+            assert key in cfg and key in cfg.get("published", {}), key
     for w in ALL["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["config"] in CONFIGS and w["chips"] == 1
+        assert w["config"] in CONFIGS and w["chips"] in (1, 4)
         assert len(w["why"]) <= 200
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(len(BENCH["workloads"]) // 2, 1)
     assert {c["name"] for c in BENCH["configs"]} == {
         w["config"] for w in BENCH["workloads"]}
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
@@ -270,36 +296,24 @@ def test_comparison_passes_equal_and_fails_one_flipped_count(case):
     assert any(numbers[k] > limits[k] for k in limits)
 
 
+@pytest.mark.parametrize("kind", sorted({kind_of(c) for c in CONFIGS}))
+def test_kind_hooks_found_by_name(kind):
+    """Every configuration's kind brings its test hooks; a kind without
+    them fails here by name, and falls into no other kind's."""
+    path = os.path.join(common.BENCH, "kinds", kind + ".py")
+    assert os.path.isfile(path), f"kind {kind!r} has no hooks: no {path}"
+    h = hooks(kind)
+    for name in ("tiny", "control_numbers", "plant"):
+        assert callable(getattr(h, name, None)), f"{path} has no {name}()"
+    assert h.FAULTS, f"{path} plants no fault"
+
+
 @pytest.mark.parametrize("config", sorted(CONFIGS))
 def test_control_fails_the_limits(config):
-    """The reference with bf16x3 projections must fail, at widths where a
-    rounding of the projections flips some signs."""
-    from bench.traffic import openloop, stream
+    """The control, one precision step down at a test size (the kind's
+    ``control_numbers``), must fail the configuration's limits."""
     limits = common.load_json(ROOT, CONFIGS[config]["file"])["limits"]
-    if config == "kdd99-http-stream":
-        x = stream.standin(5, 19600, 40, 36, 5)
-        w = np.random.default_rng(1).standard_normal((37, 768))
-        out = {}
-        for prec, dt in (("float64", np.float64), ("bf16x3", np.float32)):
-            b = ace.hash_buckets(ace.features(x[:, None, :], 0.25, dt),
-                                 w.astype(np.float32), 15, 50, prec)
-            out[prec] = stream_ref.replay(b, 392, 150, num_bits=15,
-                                          alpha=1.0, warmup=512, chunk_T=2,
-                                          topk=8)
-        numbers = stream_ref.compare(out["bf16x3"], out["float64"])
-    else:
-        pool, tid = openloop.request_pool(3, 2048, 4, 1024, 8, 1.1, 0.05)
-        w = np.random.default_rng(2).standard_normal((1025, 768))
-        g = np.random.default_rng(4)
-        batches = [(rows, tid[rows]) for rows in
-                   (g.integers(0, 2048, 100) for _ in range(400))]
-        out = {}
-        for prec, dt in (("float64", np.float64), ("bf16x3", np.float32)):
-            b = ace.hash_buckets(ace.features(pool, 0.25, dt),
-                                 w.astype(np.float32), 15, 50, prec)
-            out[prec] = fleet_ref.replay(batches, b, num_tenants=8,
-                                         num_bits=15, alpha=1.0, warmup=64)
-        numbers = fleet_ref.compare(out["bf16x3"], out["float64"])
+    numbers = hooks(kind_of(config)).control_numbers()
     assert any(numbers[k] > limits[k] for k in limits), numbers
 
 
@@ -313,56 +327,33 @@ def test_tiny_run_is_correct(cell, cache):
     json.dumps(res)
 
 
-def _stream_faults(fault, monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.stream import StreamRunner
-
-    orig = StreamRunner.consume
-
-    def consume(self, state, w, feats, *a, **k):
-        if fault == "half_batch":
-            feats = feats.at[:, feats.shape[1] // 2:].set(jnp.nan)
-        kept = jax.tree.map(jnp.copy, state)
-        new, summ, keep = orig(self, state, w, feats, *a, **k)
-        if fault == "answer":
-            keep = keep.at[0, 0].set(~keep[0, 0])
-        return (kept if fault == "state_unchanged" else new), summ, keep
-
-    monkeypatch.setattr(StreamRunner, "consume", consume)
+@pytest.mark.parametrize("cell", ALL_CELLS)
+def test_tiny_traced_run_reads_its_metrics(cell, cache):
+    """A traced run hands its readers both reductions of the trace; what
+    they read is the cell's own per-layer metrics, each a finite number."""
+    res = run.run_cell(cell, 2**31 + 13, 1.0, True, require_tpu=False,
+                       **tiny(cell))
+    assert res["correct"], res["checks"]
+    wanted = {m["name"] for m in run.cell_metrics(cell, ALL, trace=True)}
+    assert set(res["metrics"]) <= wanted
+    assert all(np.isfinite(m["value"]) for m in res["metrics"].values())
+    assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
 
 
-def _guard_faults(fault, monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.serve.engine import Guardrail
-
-    orig = Guardrail.admit
-
-    def admit(self, embeds, tenant_ids=None):
-        if fault == "half_batch":
-            embeds = embeds.at[embeds.shape[0] // 2:].set(jnp.nan)
-        kept = jax.tree.map(jnp.copy, self.state)
-        out = orig(self, embeds, tenant_ids)
-        if fault == "state_unchanged":
-            self.state = kept
-        if fault == "answer":
-            out = out.copy()
-            out[0] = ~out[0]
-        return out
-
-    monkeypatch.setattr(Guardrail, "admit", admit)
+def test_traced_run_reads_the_program_spans(cache):
+    """On the CPU the program's consume spans are in the trace (its device
+    scopes are not: no ``/device:*`` plane), and reach the readers through
+    ``Context.scopes``."""
+    res = run.run_cell("stream-kdd99-chunk", 2**31 + 14, 1.0, True,
+                       require_tpu=False, **tiny("stream-kdd99-chunk"))
+    assert res["metrics"]["runner_dispatch_us.stream"]["value"] > 0
 
 
-@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
-                                   "answer"])
-@pytest.mark.parametrize("cell", ["stream-kdd99-chunk",
-                                  "guard-fleet64-steady"])
+@pytest.mark.parametrize("cell,fault", FAULT_CASES,
+                         ids=[f"{c}-{f}" for c, f in FAULT_CASES])
 def test_planted_fault_is_not_correct(cell, fault, cache, monkeypatch):
-    plant = _stream_faults if cell.startswith("stream") else _guard_faults
-    plant(fault, monkeypatch)
+    w = {w["name"]: w for w in ALL["workloads"]}[cell]
+    hooks(kind_of(w["config"])).plant(fault, monkeypatch)
     res = run.run_cell(cell, 2**31 + 12, 1.0, False, require_tpu=False,
                        **tiny(cell))
     assert not res["correct"], res["checks"]

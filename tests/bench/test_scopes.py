@@ -6,6 +6,8 @@ program's ``ace:`` spans are in it."""
 from __future__ import annotations
 
 import collections
+import dataclasses
+import json
 import os
 import shutil
 import subprocess
@@ -241,12 +243,65 @@ def test_reduce_of_a_recorded_trace(tmp_path):
                                                (430, 690)]}
     assert s.pulls == [(165, 400), (425, 700), (705, 900)]
     assert scopes.readings(s) == pytest.approx({
-        "score_device_us_per_kitem.stream": 0.8,
-        "rescore_device_us_per_kitem.stream": 0.8,
-        "insert_device_us_per_kitem.stream": 0.5,
-        "unscoped_device_share.stream": 100 * 20 / 440,
-        "runner_dispatch_us.stream": 0.01,
-        "pull_after_ready_ms.stream": 260e-6})
+        "score_device_us_per_kitem": 0.8,
+        "rescore_device_us_per_kitem": 0.8,
+        "insert_device_us_per_kitem": 0.5,
+        "unscoped_device_share": 100 * 20 / 440,
+        "runner_dispatch_us": 0.01,
+        "pull_after_ready_ms": 260e-6})
+
+
+# The admitted metrics that read the program's marks, and their readings.
+SCOPE_METRICS = {
+    "score_device_us_per_kitem.stream": "score_device_us_per_kitem",
+    "rescore_device_us_per_kitem.stream": "rescore_device_us_per_kitem",
+    "insert_device_us_per_kitem.stream": "insert_device_us_per_kitem",
+    "unscoped_device_share.stream": "unscoped_device_share",
+    "runner_dispatch_us.stream": "runner_dispatch_us",
+    "pull_after_ready_ms.stream": "pull_after_ready_ms"}
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_metric_file_reads_its_key_from_the_scopes(metric, tmp_path):
+    """Each reader takes its reading from ``Context.scopes``, the reduction
+    ``bench/run.py`` hands it, and reads nothing from a program without
+    the marks."""
+    from bench import run
+    read = common.load_module(os.path.join(
+        common.BENCH, "metrics", metric + ".py")).read
+    s = scopes.reduce(_trace_file(tmp_path))
+    ctx = run.Context(trace=None, scopes=s, samples={}, cfg={}, mix={},
+                      work=None, peaks={})
+    assert read(ctx) == pytest.approx(scopes.readings(s)[
+        SCOPE_METRICS[metric]])
+    bare = scopes.ScopeSummary(window_s=1.0, busy_s=0.5,
+                               scope_self_s={"unscoped": 0.5}, spans=[],
+                               modules={}, pulls=[])
+    assert read(dataclasses.replace(ctx, scopes=bare)) is None
+
+
+def test_readings_take_any_phase_found():
+    """A scope the program adds later (``ace/rotate``) is read by its name,
+    with no change here."""
+    span = (0, 10, scopes.CONSUME_SPAN, {"chunk": 1, "items": 2000})
+    s = scopes.ScopeSummary(window_s=1.0, busy_s=0.5,
+                            scope_self_s={"rotate": 1e-3, "score": 3e-3,
+                                          "unscoped": 1e-3},
+                            spans=[span], modules={}, pulls=[])
+    assert scopes.readings(s) == pytest.approx({
+        "rotate_device_us_per_kitem": 500.0,
+        "score_device_us_per_kitem": 1500.0,
+        "unscoped_device_share": 20.0,
+        "runner_dispatch_us": 0.01})
+
+
+def test_main_reduces_a_kept_file(tmp_path, capsys):
+    path = _trace_file(tmp_path)
+    assert scopes.main(["--file", path]) == 0
+    got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert got["readings"] == pytest.approx(
+        scopes.readings(scopes.reduce(path)))
+    assert got["busy_s"] == pytest.approx(440e-9)
 
 
 def test_reader_imports_neither_tensorflow_nor_xprof(tmp_path):
@@ -466,14 +521,20 @@ def test_program_spans_share_the_device_clock(cpu_trace):
 
 
 def test_hlo_phases_of_the_cpu_consume_module(cpu_trace):
+    """The phases in the HLO proto of the consume program that ran in the
+    trace.  The profiler lists the proto of every executable alive in the
+    process, so another runner's, left by an earlier test, may be there
+    too: the one that ran is found by its program id."""
     with open(cpu_trace, "rb") as f:
         buf = f.read()
+    (ran,) = {st["program_id"] for *_, st in _host_events(cpu_trace)
+              if st.get("hlo_module") == scopes.CONSUME_MODULE}
     (proto,) = [st["Hlo Proto"]
                 for num, v in scopes.fields(buf) if num == 1
                 for name, events in [scopes._plane_metadata(buf, v)]
                 if name == "/host:metadata"
                 for ename, _, st in events
-                if ename.startswith(scopes.CONSUME_MODULE + "(")]
+                if ename == f"{scopes.CONSUME_MODULE}({ran})"]
     phases = set(scopes.hlo_phases(buf, proto).values())
     assert {"hash", "score", "threshold", "insert", "rescore",
             "summary"} <= phases

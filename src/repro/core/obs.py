@@ -17,8 +17,9 @@ find the program's own marks among XLA's op names and the caller's spans:
 
 The phases: ``features``, ``hash``, ``score`` (the pre-insert gather),
 ``threshold``, ``insert`` (the scatter-add), ``rescore`` (the post-insert
-counts, gathered or derived from the score's gather, and the Welford fold)
-and ``summary`` (the per-chunk report).
+counts, gathered or derived from the score's gather, and the Welford fold),
+``summary`` (the per-chunk report) and, in a sliding window, ``rotate`` (the
+ring's advance, once per epoch).
 """
 from __future__ import annotations
 

@@ -89,6 +89,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core import sketch as sk
 from repro.core.sketch import AceConfig, AceState
 
@@ -226,38 +227,39 @@ def rotate(state: WindowedAceState, gamma: float = 1.0) -> WindowedAceState:
     all-zero init with the cursor back where it started
     (property-tested).
     """
-    E = state.num_epochs
-    new_cursor = jnp.mod(state.cursor + 1, E)
-    zero_slab = jnp.zeros(state.counts.shape[1:], state.counts.dtype)
-    counts = jax.lax.dynamic_update_index_in_dim(
-        state.counts, zero_slab, new_cursor, axis=0)
-    w = epoch_weights(new_cursor, E, gamma)
-    tail = jnp.tensordot(w, counts.astype(jnp.float32), axes=1)
-    zero1 = jnp.zeros((1,), jnp.float32)
-    qhist = state.qhist
-    if qhist is not None:
-        qhist = jax.lax.dynamic_update_index_in_dim(
-            qhist, jnp.zeros((qhist.shape[1],), jnp.float32),
-            new_cursor, axis=0)
-    attr = state.attr
-    if attr is not None:
-        attr = jax.lax.dynamic_update_index_in_dim(
-            attr, jnp.zeros(attr.shape[1:], jnp.float32),
-            new_cursor, axis=0)
-    return WindowedAceState(
-        counts=counts,
-        n=jax.lax.dynamic_update_slice(state.n, zero1, (new_cursor,)),
-        welford_mean=jax.lax.dynamic_update_slice(
-            state.welford_mean, zero1, (new_cursor,)),
-        welford_m2=jax.lax.dynamic_update_slice(
-            state.welford_m2, zero1, (new_cursor,)),
-        tail=tail,
-        ssq=jnp.sum(tail * tail),
-        cursor=new_cursor,
-        tick=state.tick,
-        qhist=qhist,
-        attr=attr,
-    )
+    with obs.scope("rotate"):
+        E = state.num_epochs
+        new_cursor = jnp.mod(state.cursor + 1, E)
+        zero_slab = jnp.zeros(state.counts.shape[1:], state.counts.dtype)
+        counts = jax.lax.dynamic_update_index_in_dim(
+            state.counts, zero_slab, new_cursor, axis=0)
+        w = epoch_weights(new_cursor, E, gamma)
+        tail = jnp.tensordot(w, counts.astype(jnp.float32), axes=1)
+        zero1 = jnp.zeros((1,), jnp.float32)
+        qhist = state.qhist
+        if qhist is not None:
+            qhist = jax.lax.dynamic_update_index_in_dim(
+                qhist, jnp.zeros((qhist.shape[1],), jnp.float32),
+                new_cursor, axis=0)
+        attr = state.attr
+        if attr is not None:
+            attr = jax.lax.dynamic_update_index_in_dim(
+                attr, jnp.zeros(attr.shape[1:], jnp.float32),
+                new_cursor, axis=0)
+        return WindowedAceState(
+            counts=counts,
+            n=jax.lax.dynamic_update_slice(state.n, zero1, (new_cursor,)),
+            welford_mean=jax.lax.dynamic_update_slice(
+                state.welford_mean, zero1, (new_cursor,)),
+            welford_m2=jax.lax.dynamic_update_slice(
+                state.welford_m2, zero1, (new_cursor,)),
+            tail=tail,
+            ssq=jnp.sum(tail * tail),
+            cursor=new_cursor,
+            tick=state.tick,
+            qhist=qhist,
+            attr=attr,
+        )
 
 
 def maybe_rotate(state: WindowedAceState, rotate_every: int,
@@ -318,17 +320,18 @@ def window_table_sums(state: WindowedAceState, buckets: jax.Array,
     ``score_live`` combine, which divides by the healthy count.
     """
     E, L, nbuckets = state.counts.shape
-    rows = jnp.broadcast_to(
-        jnp.arange(L, dtype=jnp.int32)[None, :], buckets.shape)
-    ring_rows = rows + state.cursor * L
-    flat = state.counts.reshape(E * L, nbuckets)
-    tail_g = state.tail[rows, buckets]                           # (B, L)
-    live_g = flat[ring_rows, buckets].astype(jnp.float32)        # (B, L)
-    if table_mask is not None:
-        maskf = table_mask.astype(jnp.float32)
-        tail_g = tail_g * maskf
-        live_g = live_g * maskf
-    return jnp.sum(tail_g, axis=-1), jnp.sum(live_g, axis=-1)
+    with obs.scope("score"):
+        rows = jnp.broadcast_to(
+            jnp.arange(L, dtype=jnp.int32)[None, :], buckets.shape)
+        ring_rows = rows + state.cursor * L
+        flat = state.counts.reshape(E * L, nbuckets)
+        tail_g = state.tail[rows, buckets]                       # (B, L)
+        live_g = flat[ring_rows, buckets].astype(jnp.float32)    # (B, L)
+        if table_mask is not None:
+            maskf = table_mask.astype(jnp.float32)
+            tail_g = tail_g * maskf
+            live_g = live_g * maskf
+        return jnp.sum(tail_g, axis=-1), jnp.sum(live_g, axis=-1)
 
 
 def score_live(tail_sums: jax.Array, live_sums: jax.Array,
@@ -344,10 +347,11 @@ def score_live(tail_sums: jax.Array, live_sums: jax.Array,
     With ``table_mask`` the sums are assumed already masked (from the
     masked ``window_table_sums``) and the reciprocal is 1/num_healthy —
     the degraded-mode mean over surviving tables."""
-    if table_mask is None:
-        return (tail_sums + live_sums) * jnp.float32(1.0 / num_tables)
-    nh = jnp.maximum(jnp.sum(table_mask.astype(jnp.float32)), 1.0)
-    return (tail_sums + live_sums) * (1.0 / nh)
+    with obs.scope("score"):
+        if table_mask is None:
+            return (tail_sums + live_sums) * jnp.float32(1.0 / num_tables)
+        nh = jnp.maximum(jnp.sum(table_mask.astype(jnp.float32)), 1.0)
+        return (tail_sums + live_sums) * (1.0 / nh)
 
 
 def score_combined(state: WindowedAceState,
@@ -400,45 +404,47 @@ def insert_current(state: WindowedAceState, buckets: jax.Array,
     tail_sums, live_pre = pre_sums
 
     # -- THE scatter (live epoch rows of the ring)
-    w_ctr = jnp.broadcast_to(
-        mask.astype(state.counts.dtype)[:, None], buckets.shape)
-    new_ring = state.counts.reshape(E * L, nbuckets) \
-        .at[ring_rows, buckets].add(w_ctr).reshape(state.counts.shape)
+    with obs.scope("insert"):
+        w_ctr = jnp.broadcast_to(
+            mask.astype(state.counts.dtype)[:, None], buckets.shape)
+        new_ring = state.counts.reshape(E * L, nbuckets) \
+            .at[ring_rows, buckets].add(w_ctr).reshape(state.counts.shape)
 
-    # -- post-insert windowed sums/scores (tail unchanged; same float
-    #    sequence as sketch.batch_scores: row-sum, add, ONE 1/L
-    #    reciprocal multiply)
-    live_post = jnp.sum(
-        new_ring.reshape(E * L, nbuckets)[ring_rows, buckets]
-        .astype(jnp.float32), axis=-1)
-    scores = score_live(tail_sums, live_post, L)
+    with obs.scope("rescore"):
+        # -- post-insert windowed sums/scores (tail unchanged; same float
+        #    sequence as sketch.batch_scores: row-sum, add, ONE 1/L
+        #    reciprocal multiply)
+        live_post = jnp.sum(
+            new_ring.reshape(E * L, nbuckets)[ring_rows, buckets]
+            .astype(jnp.float32), axis=-1)
+        scores = score_live(tail_sums, live_post, L)
 
-    # -- ssq increment from masked pre/post sums only
-    m_tail = jnp.sum(tail_sums * maskf)
-    m_pre = jnp.sum(live_pre * maskf)
-    m_post = jnp.sum(live_post * maskf)
-    new_ssq = state.ssq + 2.0 * m_tail + m_pre + m_post
+        # -- ssq increment from masked pre/post sums only
+        m_tail = jnp.sum(tail_sums * maskf)
+        m_pre = jnp.sum(live_pre * maskf)
+        m_post = jnp.sum(live_post * maskf)
+        new_ssq = state.ssq + 2.0 * m_tail + m_pre + m_post
 
-    # -- per-epoch Welford fold of windowed post-insert rates; mirrors
-    #    sketch.masked_batch_welford with the epoch's n as the stream
-    #    length and the WINDOW's n as the rate normaliser (equal when
-    #    E=1 — bitwise the flat fold)
-    b = jnp.sum(maskf)
-    n_e = jnp.take(state.n, state.cursor)
-    tot_e = n_e + b
-    n_w = combined_n(state, gamma) + b
-    rates = scores / jnp.maximum(n_w, 1.0)
-    mean_b = jnp.sum(rates * maskf) / jnp.maximum(b, 1.0)
-    m2_b = jnp.sum(((rates - mean_b) ** 2) * maskf)
-    new_mean, new_m2 = sk.welford_fold(
-        jnp.take(state.welford_mean, state.cursor),
-        jnp.take(state.welford_m2, state.cursor),
-        n_e, b, tot_e, mean_b, m2_b, cfg.welford_min_n)
-    has = b > 0
-    new_mean = jnp.where(has, new_mean,
-                         jnp.take(state.welford_mean, state.cursor))
-    new_m2 = jnp.where(has, new_m2,
-                       jnp.take(state.welford_m2, state.cursor))
+        # -- per-epoch Welford fold of windowed post-insert rates; mirrors
+        #    sketch.masked_batch_welford with the epoch's n as the stream
+        #    length and the WINDOW's n as the rate normaliser (equal when
+        #    E=1 — bitwise the flat fold)
+        b = jnp.sum(maskf)
+        n_e = jnp.take(state.n, state.cursor)
+        tot_e = n_e + b
+        n_w = combined_n(state, gamma) + b
+        rates = scores / jnp.maximum(n_w, 1.0)
+        mean_b = jnp.sum(rates * maskf) / jnp.maximum(b, 1.0)
+        m2_b = jnp.sum(((rates - mean_b) ** 2) * maskf)
+        new_mean, new_m2 = sk.welford_fold(
+            jnp.take(state.welford_mean, state.cursor),
+            jnp.take(state.welford_m2, state.cursor),
+            n_e, b, tot_e, mean_b, m2_b, cfg.welford_min_n)
+        has = b > 0
+        new_mean = jnp.where(has, new_mean,
+                             jnp.take(state.welford_mean, state.cursor))
+        new_m2 = jnp.where(has, new_m2,
+                           jnp.take(state.welford_m2, state.cursor))
 
     c = state.cursor
     return state._replace(
@@ -652,19 +658,20 @@ def admit_threshold_windowed(state: WindowedAceState, gamma: float,
     window instead of pinning the threshold forever.  Pure device
     scalar ops — no host sync.
     """
-    n_w = combined_n(state, gamma)
-    if threshold_mode == "quantile":
-        from repro.quantile import sketch as qsk
-        t = qsk.hist_quantile(combined_qhist(state, gamma), q) \
+    with obs.scope("threshold"):
+        n_w = combined_n(state, gamma)
+        if threshold_mode == "quantile":
+            from repro.quantile import sketch as qsk
+            t = qsk.hist_quantile(combined_qhist(state, gamma), q) \
+                * jnp.maximum(n_w, 1.0)
+            return jnp.where(n_w >= warmup_items, t, -jnp.inf)
+        if threshold_mode != "mu_sigma":
+            raise ValueError(f"unknown threshold_mode {threshold_mode!r}")
+        rate = mean_mu_windowed(state, gamma, table_mask=table_mask) \
+            / jnp.maximum(n_w, 1.0)
+        t = (rate - alpha * sigma_windowed(state, gamma)) \
             * jnp.maximum(n_w, 1.0)
         return jnp.where(n_w >= warmup_items, t, -jnp.inf)
-    if threshold_mode != "mu_sigma":
-        raise ValueError(f"unknown threshold_mode {threshold_mode!r}")
-    rate = mean_mu_windowed(state, gamma, table_mask=table_mask) \
-        / jnp.maximum(n_w, 1.0)
-    t = (rate - alpha * sigma_windowed(state, gamma)) \
-        * jnp.maximum(n_w, 1.0)
-    return jnp.where(n_w >= warmup_items, t, -jnp.inf)
 
 
 def combined_ace(state: WindowedAceState) -> AceState:

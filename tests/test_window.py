@@ -3,7 +3,9 @@ streams, windowed moments), degenerate-case bitwise contracts, the
 stream runner's in-scan rotation (chunk ≡ sequential, no retraces, no
 extra transfers), the windowed guardrail, dist-layout parity on a fake
 2-device mesh, and checkpoint round-tripping of the ring state."""
+import functools
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -483,3 +485,96 @@ class TestWindowCheckpoint:
         bb = ring.insert_current(st, b, m, cfg)
         assert bool(jnp.all(a.counts == bb.counts))
         assert float(a.ssq) == float(bb.ssq)
+
+
+# ---------------------------------------------------------------------------
+# Phase scopes (repro.core.obs) on the windowed stream path.
+# ---------------------------------------------------------------------------
+
+_SCOPE_FILTER = dict(d_model=8, num_bits=7, num_tables=5, warmup_items=16.0,
+                     num_epochs=3, rotate_every=2, insert_all=True)
+_TABLE = 5 << 7            # L * 2^K: every ring or tail operand is a multiple
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.-]+) ")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.-]+ = \S+ ([\w-]+)\((.*)$")
+_CALLS = re.compile(r"(?:to_apply|body|condition|calls|true_computation|"
+                    r"false_computation)=%([\w.-]+)|"
+                    r"branch_computations=\{([^}]*)\}")
+
+
+def _scoped_ops(text: str):
+    """(opcode, operand element counts, scoped) of every instruction of a
+    pre-optimization HLO module printed with metadata and operand shapes;
+    an instruction is scoped when its own ``op_name`` or that of an
+    instruction calling its computation holds an ``ace/`` scope."""
+    comp, rows, callers = None, [], {}
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        own = bool(name and "ace/" in name.group(1))
+        sizes = [int(np.prod([int(x) for x in dims.split(",") if x]))
+                 for dims in re.findall(r"\w+\[([\d,]*)\]\S* %", m.group(2))]
+        rows.append((comp, m.group(1), sizes, own))
+        for one, many in _CALLS.findall(line):
+            for callee in ([one] if one else
+                           re.findall(r"%([\w.-]+)", many)):
+                callers.setdefault(callee, []).append((comp, own))
+    memo = {}
+
+    def scoped(c):
+        if c not in memo:
+            memo[c] = False
+            up = callers.get(c, [])
+            memo[c] = bool(up) and all(own or scoped(cc) for cc, own in up)
+        return memo[c]
+
+    return [(op, sizes, own or scoped(c)) for c, op, sizes, own in rows]
+
+
+@functools.lru_cache(maxsize=None)
+def _window_programs():
+    """Pre-optimization HLO text of the windowed consume program (R | T,
+    rotation at a segment boundary) and of the filter's featurization."""
+    from jax._src.lib import xla_client as xc
+
+    opts = xc._xla.HloPrintOptions()
+    opts.print_metadata = True
+    opts.print_operand_shape = True
+    filt = WindowedAceFilter(**_SCOPE_FILTER)
+    runner = StreamRunner(filt, chunk_T=4, topk=4, return_masks=True)
+    state, w = runner.init()
+    feats = jnp.ones((4, 16, 9), jnp.float32)
+    texts = []
+    for lowered in (runner._consume.lower(state, w, feats),
+                    jax.jit(filt.features).lower(
+                        jnp.ones((16, 2, 8), jnp.float32))):
+        texts.append(lowered.compiler_ir("hlo").as_hlo_module().to_string(
+            opts))
+    return tuple(texts)
+
+
+class TestWindowScopes:
+    def test_phases_are_the_stream_phases_and_rotate(self):
+        phases = set()
+        for text in _window_programs():
+            phases |= set(re.findall(r'op_name="(?:[^"]*/)?ace/([\w.-]+)/',
+                                     text))
+        assert phases == {"features", "hash", "score", "threshold",
+                          "insert", "rescore", "rotate", "summary"}, phases
+
+    def test_ring_and_tail_ops_carry_a_scope(self):
+        """Every gather, scatter, dot and reduce that reads the ring or the
+        tail (an operand of a multiple of L * 2^K elements) is in an
+        ``ace/`` scope; and there are such ops of each kind."""
+        ops = [(op, own) for op, sizes, own in _scoped_ops(
+            _window_programs()[0])
+            if op in ("gather", "scatter", "dot", "reduce")
+            and any(s and s % _TABLE == 0 for s in sizes)]
+        assert {op for op, _ in ops} == {"gather", "scatter", "dot",
+                                         "reduce"}, ops
+        assert all(own for _, own in ops), ops
